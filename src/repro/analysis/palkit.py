@@ -166,7 +166,7 @@ class ScratchInfo:
 
 class KernelRecord:
     """One audited ``pallas_call``: grid + blocks + scratch + body jaxpr,
-    extracted from the eqn params (JAX 0.4.x pallas internals)."""
+    extracted from the eqn params (JAX 0.9 pallas internals)."""
 
     def __init__(self, name: str, family: str, eqn):
         gm = eqn.params["grid_mapping"]
@@ -182,15 +182,14 @@ class KernelRecord:
             is_out = i >= self.num_inputs
             role = (f"out{i - self.num_inputs}" if is_out else f"in{i}")
             aval = bm.block_aval
-            asd = getattr(bm, "array_shape_dtype", None)
             trivial = bm.has_trivial_window
             if callable(trivial):
                 trivial = trivial()
             self.blocks.append(BlockInfo(
                 role=role,
-                block_shape=tuple(int(d) if isinstance(d, int) else 1
+                block_shape=tuple(_block_dim(d)
                                   for d in (bm.block_shape or ())),
-                array_shape=tuple(getattr(asd, "shape", ()) or ()),
+                array_shape=tuple(bm.array_aval.shape),
                 itemsize=int(getattr(getattr(aval, "dtype", None),
                                      "itemsize", 0) or 0),
                 space=_space_str(aval),
@@ -244,6 +243,13 @@ class KernelRecord:
         return blocks, scratch
 
 
+def _block_dim(d) -> int:
+    """One block dim as an int: ``Blocked``/``Element`` carry ``block_size``;
+    squeezed dims count as 1."""
+    size = getattr(d, "block_size", d)
+    return int(size) if isinstance(size, int) else 1
+
+
 def _space_str(aval) -> str:
     ms = getattr(aval, "memory_space", None)
     return "vmem" if ms is None else str(ms)
@@ -259,8 +265,8 @@ def _jx(j):
 
 
 def _is_literal(v) -> bool:
-    from jax import core
-    return isinstance(v, core.Literal)
+    from jax.extend.core import Literal
+    return isinstance(v, Literal)
 
 
 def _is_ref(v) -> bool:

@@ -90,7 +90,7 @@ DEFAULT_SRC = os.path.join(_ROOT, "src")
 DEFAULT_TOLERANCE = 0.10
 
 _CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback",
-                   "callback"}
+                   "debug_print", "callback"}
 
 _ALLOW_RE = re.compile(
     r"#\s*tracekit:\s*allow\(([A-Za-z0-9, ]+)\)\s+entry=(\S+)\s*(.*)$")
@@ -419,7 +419,7 @@ _BUDGET_FIELDS = ("flops", "bytes_accessed", "peak_bytes")
 
 
 def _sig_digest(rec: AuditRecord) -> str:
-    # Deliberately excludes the jax version (unlike the AOT disk key): a
+    # Deliberately excludes the jax version (unlike the span digest): a
     # toolchain bump should show up as a budget DIFF, not a key change
     # that silently orphans every committed budget.
     text = "|".join([repr(rec.sig), str(rec.key[2]), str(rec.key[3]),

@@ -21,10 +21,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import stages
-from repro.compat import NamedSharding, P, shard_map
 from repro.core import hier, stream
 from repro.core import semiring as sr_mod
 from repro.core.hier import HierAssoc
@@ -52,12 +52,22 @@ def instance_assignment(n_instances: int, n_devices: int) -> jnp.ndarray:
 
 
 def create_instances(n_instances: int, cuts: Tuple[int, ...], block_size: int,
-                     dtype=jnp.float32, sr: Semiring = sr_mod.PLUS_TIMES
-                     ) -> HierAssoc:
-    """Instance-batched hierarchy pytree (leading axis = instance)."""
-    one = hier.create(cuts, block_size, dtype, sr)
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (n_instances,) + x.shape), one)
+                     dtype=jnp.float32, sr: Semiring = sr_mod.PLUS_TIMES,
+                     sharding=None) -> HierAssoc:
+    """Instance-batched hierarchy pytree (leading axis = instance).
+
+    One compiled program broadcasts a single empty instance over the fleet
+    and writes every leaf where ``sharding`` places it (default: the
+    default device).  Split on the instance axis, a fleet larger than one
+    device's memory never lands whole on the first device."""
+    sig = stages.signature_of(cuts=cuts, block_size=block_size, dtype=dtype,
+                              sr=sr)
+    broadcast = stages.wrap(
+        lambda one: jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (n_instances,) + x.shape), one),
+        "distributed.create_instances", sig, static=(n_instances,),
+        out_shardings=sharding)
+    return broadcast(hier.create(cuts, block_size, dtype, sr))
 
 
 def sharded_ingest_fn(mesh: Mesh, data_axes: Tuple[str, ...],
@@ -165,8 +175,11 @@ def global_degree_histogram_fn(mesh: Mesh, data_axes: Tuple[str, ...],
 
     Per-instance row reductions -> local histogram -> psum over the mesh.
     This is the "sum all layers / reduce globally" analytics pattern of §II.
+    Local instances run ``analytics.instance_batch(num_rows)`` at a time so
+    the dense [num_rows] degree vectors stay bounded at any fleet size.
     """
     from repro.core import assoc
+    from repro.query import analytics
 
     spec = P(data_axes)
 
@@ -178,12 +191,15 @@ def global_degree_histogram_fn(mesh: Mesh, data_axes: Tuple[str, ...],
             deg = assoc.reduce_rows(merged, num_rows, sr)
             counts = jnp.zeros((num_bins,), jnp.int32)
             nz = deg > 0
-            bins = jnp.clip(
-                jnp.floor(jnp.log2(jnp.maximum(deg, 1))).astype(jnp.int32),
-                0, num_bins - 1)
+            # bin = floor(log2(deg)) from frexp's exact exponent: the v5e's
+            # log2 puts 2^15 in bin 14
+            _, exp = jnp.frexp(jnp.maximum(deg, 1))
+            bins = jnp.clip(exp - 1, 0, num_bins - 1)
             return counts.at[bins].add(nz.astype(jnp.int32))
 
-        local = jax.vmap(one_instance)(states).sum(axis=0)
+        local = jax.lax.map(
+            one_instance, states,
+            batch_size=analytics.instance_batch(num_rows)).sum(axis=0)
         for ax in data_axes:
             local = jax.lax.psum(local, ax)
         return local

@@ -68,15 +68,29 @@ def rmat_stream(key: jax.Array, n_blocks: int, block_size: int, scale: int,
 
 def instance_streams(key: jax.Array, n_instances: int, n_blocks: int,
                      block_size: int, scale: int,
-                     params=GRAPH500):
+                     params=GRAPH500, sharding=None):
     """Independent streams for many instances: [I, T, B] arrays.
 
     Each instance gets a distinct fold of the key — the paper's "thousands of
-    processors each creating many different graphs".
+    processors each creating many different graphs".  One compiled program
+    generates the streams where ``sharding`` places them (default: the
+    default device); split on the instance axis, the fleet's stream never
+    lands whole on one device.  The per-bit categorical draw is fused
+    instead of materialized.
     """
-    keys = jax.random.split(key, n_instances)
-    return jax.vmap(
-        lambda k: rmat_stream(k, n_blocks, block_size, scale, params))(keys)
+    def body(key):
+        keys = jax.random.split(key, n_instances)
+        return jax.vmap(
+            lambda k: rmat_stream(k, n_blocks, block_size, scale, params))(
+                keys)
+
+    sig = stages.signature_of(
+        block_size=int(block_size),
+        extra=(("n_instances", int(n_instances)), ("n_blocks", int(n_blocks)),
+               ("scale", int(scale)),
+               ("params", tuple(float(p) for p in params))))
+    return stages.wrap(body, "data.instance_streams", sig,
+                       out_shardings=sharding)(key)
 
 
 def degree_tail_exponent(degrees) -> float:

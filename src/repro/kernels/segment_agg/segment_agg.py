@@ -99,8 +99,8 @@ def segment_sum_pallas(messages, seg_ids, tile_starts, num_tiles: int, *,
         num_scalar_prefetch=1,
         grid=(num_tiles,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),     # seg ids stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),     # messages stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),     # seg ids stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),     # messages stay in HBM
         ],
         out_specs=pl.BlockSpec((tn, d), lambda t, starts: (t, 0)),
         scratch_shapes=[

@@ -140,8 +140,6 @@ def main():
                 out_shardings=(None, param_sh)
             ).lower(params_abs, batch_abs).compile()
         cost = co.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # jax version drift, see probes
-            cost = cost[0] if cost else {}
         print(f"probe L=2 mb={mb} compiled; cost:",
               {k: f"{v:.3e}" for k, v in cost.items()
                if k in ("flops", "bytes accessed")})

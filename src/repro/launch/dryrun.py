@@ -38,8 +38,6 @@ def _cost_dict(compiled):
         c = compiled.cost_analysis()
     except Exception as e:                       # pragma: no cover
         return {"error": str(e)}
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
     return {k: float(v) for k, v in c.items()
             if isinstance(v, (int, float))}
 

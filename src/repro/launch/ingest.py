@@ -45,8 +45,6 @@ def run(args) -> dict:
     sig = stages.signature_of(cuts=cuts, block_size=args.block_size,
                               fused=fused, lazy_l0=lazy_l0, chunk=chunk,
                               use_kernel=use_kernel, batch_mode=batch_mode)
-    if getattr(args, "stages_cache", ""):
-        stages.set_cache_dir(args.stages_cache)
     obs_on = getattr(args, "obs", False)
     if obs_on:
         from repro import obs
@@ -168,9 +166,6 @@ def main():
                     "masked merge per instance; switch = legacy vmapped "
                     "lax.switch (executes every branch — the divergence "
                     "A/B baseline)")
-    ap.add_argument("--stages-cache", dest="stages_cache", default="",
-                    help="persistent compile-cache directory "
-                    "(repro.stages.set_cache_dir)")
     ap.add_argument("--precompile", action="store_true",
                     help="compile the whole dispatch set up front "
                     "(stages.precompile_fleet) before streaming")
@@ -182,6 +177,7 @@ def main():
                     help="observability output directory (default 'obs' "
                     "or REPRO_OBS_DIR)")
     args = ap.parse_args()
+    stages.set_cache_dir(stages.default_cache_dir())
     out = run(args)
     print(f"sustained {out['updates_per_s']:,.0f} updates/s over "
           f"{out['total_updates']:,} updates "

@@ -28,10 +28,10 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import stages
-from repro.compat import shard_map
 from repro.configs import D4M_SHAPES, LM_SHAPES, get_config
 from repro.distribution.sharding import (lm_param_specs, make_policy,
                                          to_shardings, use_policy)
@@ -46,8 +46,6 @@ METRICS = ("flops", "bytes", "coll")
 def extract(compiled) -> Dict[str, float]:
     from repro.roofline.hlo import collective_bytes_by_type
     c = compiled.cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
     coll, _ = collective_bytes_by_type(compiled.as_text())
     return dict(flops=float(c.get("flops", 0.0)),
                 bytes=float(c.get("bytes accessed", 0.0)),

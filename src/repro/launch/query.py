@@ -32,8 +32,6 @@ from repro.query import service
 
 def run(args) -> dict:
     cuts = tuple(int(c) for c in args.cuts.split(","))
-    if getattr(args, "stages_cache", ""):
-        stages.set_cache_dir(args.stages_cache)
     if getattr(args, "obs", False):
         from repro import obs
         obs.enable(getattr(args, "obs_dir", None) or None)
@@ -128,9 +126,6 @@ def main():
     ap.add_argument("--batch-mode", dest="batch_mode",
                     choices=("grouped", "bucketed", "branchfree", "switch"),
                     default=cfg.batch_mode)
-    ap.add_argument("--stages-cache", dest="stages_cache", default="",
-                    help="persistent compile-cache directory "
-                    "(repro.stages.set_cache_dir)")
     ap.add_argument("--precompile", action="store_true",
                     help="compile the whole dispatch set up front "
                     "(stages.precompile_fleet) before serving")
@@ -146,6 +141,7 @@ def main():
                     "counted (and emitted as obs events) per batch, and "
                     "slo_attainment lands in the stats")
     args = ap.parse_args()
+    stages.set_cache_dir(stages.default_cache_dir())
     out = run(args)
     print(f"ingest  {out['updates_per_s']:,.0f} upd/s "
           f"(ingest-only {out['ingest_only_updates_per_s']:,.0f}, "

@@ -22,7 +22,7 @@ from repro.obs.slo import RollingRate, SLOTracker, StallDetector  # noqa: F401
 from repro.obs.trace import disable, emit, enable, enabled     # noqa: F401
 
 # REPRO_OBS=1 in the environment arms tracing at first import, the same
-# convention as REPRO_STAGES_CACHE_DIR / REPRO_CHECK — reliable for CLIs
+# convention as REPRO_CHECK — reliable for CLIs
 # and CI without call-order footguns.
 if trace.env_enabled():
     trace.enable()

@@ -17,7 +17,8 @@ gates live slots by ``nnz`` (``assoc._live_slots``) instead of trusting
 slots past ``nnz`` to hold sentinel keys / zero values, matching the
 engine's ``_raw_point``/``extract_rows`` discipline.
 
-All functions are jit-safe and vmap-safe over the instance axis.
+All functions are jit-safe and vmap-safe over the instance axis; fleet-wide
+callers map them over instances in ``instance_batch`` chunks.
 """
 from __future__ import annotations
 
@@ -31,6 +32,21 @@ from repro.core import semiring as sr_mod
 from repro.core.semiring import Semiring
 
 Array = jax.Array
+
+
+# Dense per-row temporaries must not grow with the instance count: a
+# fleet-wide reduction that vmaps ``top_k_rows`` over 1,024 instances at
+# num_rows = 2^22 would hold 1,024 x 16 MiB per dense vector, more than a
+# 16 GiB v5e chip.  Callers ``lax.map`` over instances in batches of
+# ``instance_batch(num_rows)``, which keeps batch x _DENSE_VECTORS x 4 B x
+# num_rows under a quarter of that chip's HBM.
+_DENSE_BUDGET_BYTES = 4 << 30
+_DENSE_VECTORS = 8      # [num_rows] 4-byte vectors live at once per instance
+
+
+def instance_batch(num_rows: int) -> int:
+    """Instances per ``lax.map`` step for a dense [num_rows] reduction."""
+    return max(1, _DENSE_BUDGET_BYTES // (_DENSE_VECTORS * 4 * num_rows))
 
 
 def _layer_combine(sr: Semiring, parts) -> Array:

@@ -5,7 +5,8 @@ network data (arXiv:1907.04217) — which means the read path must run while
 the write path streams, without draining the hierarchy.  This module
 interleaves jitted ingest rounds (``stream.ingest_instances`` — the
 production depth-cohort grouped layout) with jitted query batches (``engine`` point
-lookups and ``analytics`` reductions, vmapped over the local instances)
+lookups vmapped over the local instances, ``analytics`` reductions mapped
+over them in bounded batches)
 and reports both sides of the ledger: sustained updates/s, queries/s and
 per-batch query latency.  Because the engine never mutates or merges
 state, the only coupling between the two paths is the device itself — the
@@ -69,13 +70,17 @@ def make_point_query_fn(sr: Semiring = sr_mod.PLUS_TIMES, *,
 
 def make_analytics_fn(num_rows: int, k: int,
                       sr: Semiring = sr_mod.PLUS_TIMES):
-    """Staged states -> (top-k totals [I, k], top-k row ids [I, k])."""
+    """Staged states -> (top-k totals [I, k], top-k row ids [I, k]).
+
+    Instances run ``analytics.instance_batch(num_rows)`` at a time, so the
+    dense [num_rows] temporaries stay bounded at any fleet size."""
     sig = stages.signature_of(sr=sr, extra=(("num_rows", int(num_rows)),
                                             ("k", int(k))))
 
     def run(s):
-        return jax.vmap(
-            lambda h: analytics.top_k_rows(h, num_rows, k, sr=sr))(s)
+        return jax.lax.map(
+            lambda h: analytics.top_k_rows(h, num_rows, k, sr=sr), s,
+            batch_size=analytics.instance_batch(num_rows))
     return stages.wrap(run, "service.analytics", sig)
 
 

@@ -1,4 +1,4 @@
-"""Staged lowering + keyed AOT compile cache — the one front door for jit.
+"""Staged lowering + keyed compile cache — the one front door for jit.
 
 The paper's deployment launches 34,000 hierarchical D4M instances at once
 (arXiv:1902.00846), which makes fleet COLD-START a first-class cost: every
@@ -22,15 +22,13 @@ single knob canonicalizer/validator: every entry point (``stream``,
 validation through it, so an invalid combination fails with the same
 error message everywhere.
 
-Persistence: compiled executables are serialized with
-``jax.experimental.serialize_executable`` (``jax.export`` is not available
-on this JAX) into ``<cache_dir>/aot/``, keyed by a content hash of the
-signature + avals + jax version/backend/device count, and
-``jax_compilation_cache_dir`` is pointed at ``<cache_dir>/xla`` as the
-fallback for programs whose executables cannot round-trip — so a fresh
-process (or CI run, see .github/workflows/ci.yml) reports cache hits
-instead of re-compiling.  Set ``REPRO_STAGES_CACHE_DIR`` or call
-``set_cache_dir`` BEFORE the first compile.
+Persistence: ``set_cache_dir(path)`` makes ``path`` JAX's persistent
+compilation cache, which keys every executable by its lowered program and
+compile options, so a fresh process (or CI run, see
+.github/workflows/ci.yml) re-lowers and then reports disk hits instead of
+re-compiling.  Launchers call ``set_cache_dir(default_cache_dir())``:
+``$JAX_COMPILATION_CACHE_DIR`` when it is set, else the fixed
+``<checkout>/.jax-cache``.  Call it BEFORE the first compile.
 
 ``precompile_fleet(cfg)`` enumerates a ``D4MConfig``'s dispatch set
 (instance-batched ingest with/without telemetry, the service query/
@@ -44,7 +42,6 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import os
-import pickle
 import threading
 import time
 from typing import Any, Callable, Optional, Tuple
@@ -239,7 +236,7 @@ def check_state(sig: Signature, h, block: Optional[int] = None) -> None:
 def _leaf_key(x):
     if isinstance(x, jax.ShapeDtypeStruct):
         return (tuple(x.shape), jnp.dtype(x.dtype).name, False)
-    aval = jax.core.raise_to_shaped(jax.core.get_aval(x))
+    aval = jax.typeof(x)
     return (tuple(aval.shape), aval.dtype.name, bool(aval.weak_type))
 
 
@@ -317,33 +314,45 @@ def _freeze(x):
 # ---------------------------------------------------------------- storage ---
 
 
-def set_cache_dir(path: Optional[str]) -> None:
-    """Point the persistence layer at ``path`` (None disables it).
+# JAX's cache settings as they stood before ``set_cache_dir`` changed them
+# (``$JAX_COMPILATION_CACHE_DIR`` is already applied by jax itself), so
+# ``set_cache_dir(None)`` hands the process back to the outside setting.
+_CACHE_CONFIG = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+_OUTSIDE_CONFIG = {k: getattr(jax.config, k) for k in _CACHE_CONFIG}
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax-cache")
 
-    Wires ``jax_compilation_cache_dir`` to ``<path>/xla`` (with the
+
+def default_cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax-cache``
+    — one fixed path, because a cache directory that moves never hits."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE
+
+
+def set_cache_dir(path: Optional[str]) -> None:
+    """Make ``path`` the persistent compile cache (None restores the outside
+    setting).
+
+    ``path`` becomes JAX's ``jax_compilation_cache_dir``, with the
     min-compile-time/min-entry-size gates opened, since the whole point is
-    caching many small per-config programs) and stores serialized AOT
-    executables under ``<path>/aot``.  Must run BEFORE the first compile of
-    the process — XLA's cache decision is memoized at first use — so prefer
-    the ``REPRO_STAGES_CACHE_DIR`` environment variable, which is applied
-    at import time.
+    caching many small per-config programs.  Run it BEFORE the first
+    compile of the process: XLA's cache decision is memoized at first use,
+    which ``reset_cache`` re-arms for a directory set mid-process.
     """
     global _CACHE_DIR
+    from jax.experimental.compilation_cache import compilation_cache
     _CACHE_DIR = os.path.abspath(path) if path else None
     if _CACHE_DIR:
-        os.makedirs(os.path.join(_CACHE_DIR, "aot"), exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(_CACHE_DIR, "xla")
-                          if _CACHE_DIR else None)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        # XLA memoizes "is the cache enabled" at first compile; re-evaluate
-        # so a cache dir set mid-process still takes effect.
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass
+        os.makedirs(_CACHE_DIR, exist_ok=True)
+        settings = dict(zip(_CACHE_CONFIG, (_CACHE_DIR, 0.0, 0)))
+    else:
+        settings = _OUTSIDE_CONFIG
+    for name, value in settings.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
 
 
 def cache_dir() -> Optional[str]:
@@ -359,53 +368,22 @@ def _digest(key) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:32]
 
 
-def _disk_path(key) -> Optional[str]:
-    if _CACHE_DIR is None:
-        return None
-    return os.path.join(_CACHE_DIR, "aot", _digest(key) + ".jaot")
+# JAX raises its persistent-cache events in the compiling thread, so
+# ``Lowered.compile`` reads them per thread to tell a disk hit from a real
+# XLA compile.  The cache itself is keyed by the lowered program and its
+# compile options: an edited program body never loads a stale executable.
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                 "/jax/compilation_cache/cache_misses": "writes"}
+_TLS = threading.local()
 
 
-def _load_disk(key):
-    path = _disk_path(key)
-    if path is None or not os.path.exists(path):
-        return None
-    try:
-        from jax.experimental import serialize_executable as se
-        with open(path, "rb") as f:
-            payload, in_tree, out_tree = pickle.load(f)
-        executable = se.deserialize_and_load(payload, in_tree, out_tree)
-    except Exception:
-        # stale/incompatible blob: fall through to a fresh compile (which
-        # overwrites the entry)
-        return None
-    comp = Compiled(key, executable, from_disk=True)
-    with _LOCK:
-        _COMPILED[key] = comp
-        _STATS["disk_hits"] += 1
-    return comp
+def _on_jax_event(event: str, **_kwargs) -> None:
+    field = _CACHE_EVENTS.get(event)
+    if field is not None:
+        setattr(_TLS, field, getattr(_TLS, field, 0) + 1)
 
 
-def _save_disk(key, executable) -> bool:
-    path = _disk_path(key)
-    if path is None:
-        return False
-    try:
-        from jax.experimental import serialize_executable as se
-        blob = pickle.dumps(se.serialize(executable))
-    except Exception:
-        # not all programs round-trip (donation/sharding edge cases on some
-        # backends); the XLA persistent cache at <dir>/xla still covers the
-        # re-compile, so this is a soft failure.
-        return False
-    tmp = f"{path}.tmp.{os.getpid()}"
-    try:
-        with open(tmp, "wb") as f:
-            f.write(blob)
-        os.replace(tmp, path)
-    except OSError:
-        return False
-    _count("disk_writes")
-    return True
+jax.monitoring.register_event_listener(_on_jax_event)
 
 
 # ----------------------------------------------------------------- stages ---
@@ -415,8 +393,9 @@ class Compiled:
     """Stage 3: an executable specialized to one (signature, avals) key.
 
     Introspection (``cost_analysis``/``as_text``/``memory_analysis``) is
-    explicit rather than pure delegation: a DESERIALIZED AOT executable
-    (``from_disk=True``) may not implement the analysis surface — instead
+    explicit rather than pure delegation: an executable JAX loaded from its
+    persistent cache (``from_disk=True``) may not implement the analysis
+    surface — instead
     of raising ``AttributeError`` into tracekit or ``stats()`` consumers,
     the methods degrade gracefully by re-lowering the entry on demand from
     the cache key's abstract avals (``abstract_args``) and answering from
@@ -461,9 +440,8 @@ class Compiled:
             return getattr(self._relowered(), name)()
 
     def cost_analysis(self) -> dict:
-        """XLA cost model for this executable, normalized to ONE dict
-        (some jax versions return a per-computation list)."""
-        return _cost_dict(self._introspect("cost_analysis"))
+        """XLA cost model for this executable."""
+        return dict(self._introspect("cost_analysis") or {})
 
     def as_text(self) -> str:
         return self._introspect("as_text")
@@ -478,18 +456,9 @@ class Compiled:
             return None
 
 
-def _cost_dict(cost) -> dict:
-    """Normalize a ``cost_analysis()`` result: jax returns a dict for
-    freshly-compiled executables but a list of per-computation dicts for
-    deserialized ones (and on some versions)."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
-
-
 class Lowered:
     """Stage 2: lowered-but-not-compiled IR for one key.  ``compile()``
-    consults the in-memory cache, then the AOT disk store, then XLA.
+    consults the in-memory cache, then XLA through JAX's persistent cache.
     Carries the closed ``jaxpr`` captured at trace time — the substrate
     tracekit's J-rules walk (a ``jax.stages.Lowered`` alone does not
     expose it)."""
@@ -505,16 +474,14 @@ class Lowered:
         if comp is not None:
             _count("memory_hits")
             return comp
-        comp = _load_disk(self.key)
-        if comp is not None:
-            return comp
+        _TLS.hits = _TLS.writes = 0
         executable = self._lowered.compile()
-        _count("compiles")
-        comp = Compiled(self.key, executable)
+        from_disk = _TLS.hits > 0
+        _count("disk_hits" if from_disk else "compiles")
+        _count("disk_writes", _TLS.writes)
         with _LOCK:
-            _COMPILED[self.key] = comp
-        _save_disk(self.key, executable)
-        return comp
+            return _COMPILED.setdefault(
+                self.key, Compiled(self.key, executable, from_disk))
 
     def __getattr__(self, name):
         return getattr(self._lowered, name)
@@ -525,7 +492,8 @@ class Wrapped:
 
     Calling it with tracers inlines the plain function (so it composes with
     jit/vmap/scan around it); calling it with concrete arrays dispatches
-    through the keyed cache: memory -> disk -> lower+compile.
+    through the keyed cache: memory, else lower + compile (which JAX's
+    persistent cache may serve from disk).
     """
 
     def __init__(self, fn: Callable, entry: str, sig: Signature,
@@ -550,14 +518,10 @@ class Wrapped:
             low = _LOWERED.get(key)
         if low is not None:
             return low
-        jitted = jax.jit(self.fn, **dict(self.jit_kwargs))
-        try:
-            # trace explicitly so the closed jaxpr is kept on the Lowered:
-            # tracekit's J-rules audit the jaxpr, not just the HLO text
-            traced = jitted.trace(*args)
-            low = Lowered(key, traced.lower(), jaxpr=traced.jaxpr)
-        except AttributeError:      # older jax: no .trace — lower directly
-            low = Lowered(key, jitted.lower(*args))
+        # trace explicitly so the closed jaxpr is kept on the Lowered:
+        # tracekit's J-rules audit the jaxpr, not just the HLO text
+        traced = jax.jit(self.fn, **dict(self.jit_kwargs)).trace(*args)
+        low = Lowered(key, traced.lower(), jaxpr=traced.jaxpr)
         with _LOCK:
             _LOWERED.setdefault(key, low)
             _STATS["lowerings"] += 1
@@ -575,13 +539,10 @@ class Wrapped:
         if comp is not None:
             _count("memory_hits")
         else:
-            comp = _load_disk(key)
-            provenance = "disk"
-            if comp is None:
-                c0 = time.perf_counter()
-                comp = self.lower(*args).compile()
-                compile_s = time.perf_counter() - c0
-                provenance = "compile"
+            c0 = time.perf_counter()
+            comp = self.lower(*args).compile()
+            compile_s = time.perf_counter() - c0
+            provenance = "disk" if comp.from_disk else "compile"
         ann = _TRACE_ANNOTATION
         if ann is not None:
             with ann(self.entry):
@@ -672,9 +633,10 @@ def reset_stats() -> None:
 
 def clear_memory_cache() -> None:
     """Drop every in-process cache entry (wrapped/lowered/compiled) but
-    leave the disk store alone — a simulated cold start: the next dispatch
-    of a persisted configuration must report a ``disk_hits`` event and zero
-    ``compiles`` (tests/test_stages.py round-trip)."""
+    leave the persistent cache alone — a simulated cold start: the next
+    dispatch of a persisted configuration re-lowers, then must report a
+    ``disk_hits`` event and zero ``compiles`` (tests/test_stages.py
+    round-trip)."""
     with _LOCK:
         _WRAPPED.clear()
         _LOWERED.clear()
@@ -693,17 +655,12 @@ def lowered_keys() -> Tuple:
 
 
 def compiled_for(wrapped: "Wrapped", *args) -> Compiled:
-    """The ``Compiled`` behind one (wrapped, args) dispatch — memory, then
-    disk, then lower+compile.  Benchmarks use this to read
-    ``cost_analysis`` off exactly the executable they just timed."""
-    key = wrapped._key(args)
+    """The ``Compiled`` behind one (wrapped, args) dispatch — memory, else
+    lower+compile.  Benchmarks use this to read ``cost_analysis`` off
+    exactly the executable they just timed."""
     with _LOCK:
-        comp = _COMPILED.get(key)
-    if comp is None:
-        comp = _load_disk(key)
-    if comp is None:
-        comp = wrapped.lower(*args).compile()
-    return comp
+        comp = _COMPILED.get(wrapped._key(args))
+    return comp if comp is not None else wrapped.lower(*args).compile()
 
 
 def cost_of(wrapped: "Wrapped", *args) -> dict:
@@ -861,9 +818,10 @@ def precompile_fleet(cfg, *, instances: Optional[int] = None,
     single-instance ``hier``/``engine`` ops, and the sharded ingest/query
     programs when ``mesh``/``data_axes`` are given — and drives each
     through lower+compile against abstract inputs.  With a warm persistent
-    cache this is pure deserialization: ``stats()["compiles"]`` stays 0
-    and a subsequent ``launch/ingest`` + ``launch/query`` run performs
-    ZERO compile events (the acceptance criterion asserted in
+    cache this is lowering plus deserialization: ``stats()["compiles"]``
+    stays 0 and a subsequent ``launch/ingest`` + ``launch/query`` run performs
+    ZERO compile events beyond its one-off set-up programs, fleet
+    construction and the synthetic stream generator (asserted in
     tests/test_stages.py).
 
     ``instances``/``blocks``/``queries`` override the config's
@@ -880,15 +838,7 @@ def precompile_fleet(cfg, *, instances: Optional[int] = None,
     report = {}
     for entry, wrapped, args in jobs:
         before = stats()
-        # consult memory/disk by key first: on a warm persistent cache the
-        # precompile pass is pure deserialization and skips even the trace
-        key = wrapped._key(args)
-        with _LOCK:
-            comp = _COMPILED.get(key)
-        if comp is None:
-            comp = _load_disk(key)
-        if comp is None:
-            wrapped.lower(*args).compile()
+        compiled_for(wrapped, *args)
         after = stats()
         if after["compiles"] > before["compiles"]:
             report[entry] = "compiled"
@@ -898,9 +848,3 @@ def precompile_fleet(cfg, *, instances: Optional[int] = None,
             report[entry] = "cached"
     return report
 
-
-# Apply the environment cache dir at import time: XLA's persistent-cache
-# decision is memoized at the first compile, so the env var is the reliable
-# way to get persistence in CLIs/CI without ordering footguns.
-if os.environ.get("REPRO_STAGES_CACHE_DIR"):
-    set_cache_dir(os.environ["REPRO_STAGES_CACHE_DIR"])

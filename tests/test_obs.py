@@ -442,7 +442,7 @@ def test_monitor_rate_agrees_with_exact_counter(tmp_path):
         instances=2, blocks=8, block_size=32, rounds=4, cuts="64,256",
         scale=10, seed=0, ckpt_dir="", ckpt_every=4, resume=False,
         verbose=False, layered=False, lazy_l0="auto", chunk=1,
-        use_kernel=False, batch_mode="grouped", stages_cache="",
+        use_kernel=False, batch_mode="grouped",
         precompile=False, obs=True, obs_dir=d)
     try:
         out = launch_ingest.run(args)

@@ -194,7 +194,7 @@ def _dma_call(kernel, sem):
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=0,
                 grid=(1,),
-                in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
                 scratch_shapes=[pltpu.VMEM((2, 8, 128), jnp.float32), sem],
             ),

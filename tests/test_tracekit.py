@@ -34,7 +34,7 @@ def _wrap(fn, name, **kw):
 def _rules_fired(wrapped, *args, acfg=None, x64=False):
     """Audit one record in isolation (no global-cache J006 scan) and
     return the set of rule ids that fired."""
-    ctx = jax.experimental.enable_x64() if x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if x64 else contextlib.nullcontext()
     with ctx, warnings.catch_warnings():
         warnings.simplefilter("ignore")   # "donated buffers not usable"
         rec = tracekit.record(wrapped, *args)
