@@ -121,9 +121,16 @@ def _canonicalize(hi: Array, lo: Array, val: Array, out_capacity: int,
     segment of the requested capacity plus an ``overflow`` count of unique
     entries dropped because they exceeded out_capacity (largest keys drop
     first, preserving the sorted prefix).
+
+    The co-sort, the value sum and the key scatters each carry a
+    ``jax.named_scope`` (``canon.sort``, ``canon.value_sum``,
+    ``canon.key_scatter``) that reaches the compiled ops' ``op_name``, so a
+    device trace can be split by phase (``stages.op_scopes``).  Scopes are
+    metadata only.
     """
     n = hi.shape[-1]
-    hi_s, lo_s, val_s = _sorted_by_key(hi, lo, val)
+    with jax.named_scope("canon.sort"):
+        hi_s, lo_s, val_s = _sorted_by_key(hi, lo, val)
 
     prev_same = jnp.concatenate([
         jnp.zeros((1,), bool),
@@ -131,15 +138,17 @@ def _canonicalize(hi: Array, lo: Array, val: Array, out_capacity: int,
     ])
     first = ~prev_same
     seg_id = jnp.cumsum(first) - 1                       # run index per slot
-    combined = sr.segment_add(val_s, seg_id, n, sorted=True)  # [n]
+    with jax.named_scope("canon.value_sum"):
+        combined = sr.segment_add(val_s, seg_id, n, sorted=True)  # [n]
 
     valid = hi_s != SENTINEL
     n_unique = jnp.sum(first & valid).astype(jnp.int32)
 
     # Scatter each run's key to its run slot.  Duplicate writes within a run
     # carry identical key values, so write order is immaterial.
-    out_hi = jnp.full((n,), SENTINEL, jnp.int32).at[seg_id].set(hi_s)
-    out_lo = jnp.full((n,), SENTINEL, jnp.int32).at[seg_id].set(lo_s)
+    with jax.named_scope("canon.key_scatter"):
+        out_hi = jnp.full((n,), SENTINEL, jnp.int32).at[seg_id].set(hi_s)
+        out_lo = jnp.full((n,), SENTINEL, jnp.int32).at[seg_id].set(lo_s)
 
     zero = sr_mod.integer_zero(sr, val.dtype)
     slot = jnp.arange(n)

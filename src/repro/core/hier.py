@@ -62,6 +62,15 @@ def layer_capacities(cuts: Tuple[int, ...], block_size: int) -> Tuple[int, ...]:
     return tuple(caps)
 
 
+def merge_width(caps: Tuple[int, ...], block: int, depth: int) -> int:
+    """Slots the fused cascade's one merge sorts for a depth-``depth`` plan,
+    over layers of capacities ``caps`` (``layer_capacities``) fed
+    ``block``-entry blocks: the incoming block plus every layer buffer in
+    [0, depth] (with ``lazy_l0`` layer 0 enters as a raw run of the same
+    width), block + C_0 + ... + C_depth."""
+    return block + sum(caps[:depth + 1])
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class HierAssoc:
@@ -384,7 +393,7 @@ def _fused_execute_planned(h: HierAssoc, rows: Array, cols: Array,
         h.layers[i] if i == 0          # depth >= 0 always: no gate needed
         else assoc.gate_segment(h.layers[i], depth >= i, sr)
         for i in range(first, up_to + 1))
-    width = raw[0].shape[-1] + sum(caps[first:up_to + 1])
+    width = merge_width(caps, B, up_to)
     seg, _ = assoc.merge_many(runs, *raw, out_capacity=width, sr=sr,
                               use_kernel=use_kernel)
     n_unique = seg.nnz

@@ -235,6 +235,11 @@ def _grouped_execute(states: HierAssoc, rows: Array, cols: Array, vals: Array,
     empty dummies carrying only the member's true nnz scalar (the executor
     reads deep layers solely for the last-layer pressure flag), so a depth-1
     iteration moves O(W_1) bytes even when C_{L-1} is huge.
+
+    Named scopes mark the layers for a device trace (``stages.op_scopes``):
+    ``cohort.d0`` is the append cohort and its select, ``cohort.d{d}`` all
+    of depth d's member loop, and inside it ``cohort.take`` / ``cohort.put``
+    the per-member slices and write-backs.
     """
     L = len(states.cuts)
     caps = tuple(l.hi.shape[-1] for l in states.layers)
@@ -254,8 +259,9 @@ def _grouped_execute(states: HierAssoc, rows: Array, cols: Array, vals: Array,
                 may_not_fit=may_not_fit))(s, rows, cols, vals, n_live)
         return _select_depth0_leaves(s, s0, take0)
 
-    # reprolint: allow(R002) batch-level cond on a per-batch scalar; this function IS the batched layout and never runs under vmap
-    cur = jax.lax.cond(jnp.any(take0), depth0_pass, lambda s: s, states)
+    with jax.named_scope("cohort.d0"):
+        # reprolint: allow(R002) batch-level cond on a per-batch scalar; this function IS the batched layout and never runs under vmap
+        cur = jax.lax.cond(jnp.any(take0), depth0_pass, lambda s: s, states)
 
     order = jnp.argsort(depths).astype(jnp.int32)
     ds = depths[order]
@@ -270,37 +276,42 @@ def _grouped_execute(states: HierAssoc, rows: Array, cols: Array, vals: Array,
             idx = order[start + j]
             pick = lambda x: jax.lax.dynamic_index_in_dim(
                 x, idx, 0, keepdims=False)
-            shallow = jax.tree.map(pick, tuple(carry.layers[:d + 1]))
-            deep = tuple(
-                dataclasses.replace(dm, nnz=pick(carry.layers[i].nnz))
-                for i, dm in zip(range(d + 1, L), dummies))
-            one = HierAssoc(layers=shallow + deep,
-                            spills=pick(carry.spills),
-                            overflow=pick(carry.overflow),
-                            n_updates=pick(carry.n_updates),
-                            n_updates_hi=pick(carry.n_updates_hi),
-                            cuts=carry.cuts)
+            with jax.named_scope("cohort.take"):
+                shallow = jax.tree.map(pick, tuple(carry.layers[:d + 1]))
+                deep = tuple(
+                    dataclasses.replace(dm, nnz=pick(carry.layers[i].nnz))
+                    for i, dm in zip(range(d + 1, L), dummies))
+                one = HierAssoc(layers=shallow + deep,
+                                spills=pick(carry.spills),
+                                overflow=pick(carry.overflow),
+                                n_updates=pick(carry.n_updates),
+                                n_updates_hi=pick(carry.n_updates_hi),
+                                cuts=carry.cuts)
+                block = tuple(pick(x) for x in (rows, cols, vals, n_live))
             out = hier._fused_execute_planned(
-                one, pick(rows), pick(cols), pick(vals), pick(n_live),
-                jnp.int32(d), up_to=d, sr=sr, use_kernel=use_kernel,
-                lazy_l0=lazy_l0)
+                one, *block, jnp.int32(d), up_to=d, sr=sr,
+                use_kernel=use_kernel, lazy_l0=lazy_l0)
             put = lambda full, v: jax.lax.dynamic_update_index_in_dim(
                 full, v, idx, 0)
-            new_shallow = jax.tree.map(put, tuple(carry.layers[:d + 1]),
-                                       tuple(out.layers[:d + 1]))
-            return dataclasses.replace(
-                carry, layers=new_shallow + carry.layers[d + 1:],
-                spills=put(carry.spills, out.spills),
-                overflow=put(carry.overflow, out.overflow),
-                n_updates=put(carry.n_updates, out.n_updates),
-                n_updates_hi=put(carry.n_updates_hi, out.n_updates_hi))
+            with jax.named_scope("cohort.put"):
+                new_shallow = jax.tree.map(put, tuple(carry.layers[:d + 1]),
+                                           tuple(out.layers[:d + 1]))
+                return dataclasses.replace(
+                    carry, layers=new_shallow + carry.layers[d + 1:],
+                    spills=put(carry.spills, out.spills),
+                    overflow=put(carry.overflow, out.overflow),
+                    n_updates=put(carry.n_updates, out.n_updates),
+                    n_updates_hi=put(carry.n_updates_hi, out.n_updates_hi))
 
-        # reprolint: allow(R002) batch-level cohort skip on a per-batch scalar count; never reached under vmap (see docstring)
-        return jax.lax.cond(
-            n_d > 0,
-            lambda s: jax.lax.fori_loop(0, n_d, body, s),
-            lambda s: s,
-            cur)
+        # the cohort's bounds stay outside its scope: XLA merges the
+        # depths' identical searchsorted loops, and with them their names
+        with jax.named_scope(f"cohort.d{d}"):
+            # reprolint: allow(R002) batch-level cohort skip on a per-batch scalar count; never reached under vmap (see docstring)
+            return jax.lax.cond(
+                n_d > 0,
+                lambda s: jax.lax.fori_loop(0, n_d, body, s),
+                lambda s: s,
+                cur)
 
     for d in range(1, L):
         cur = cohort_pass(cur, d)

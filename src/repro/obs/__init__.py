@@ -10,15 +10,15 @@ Three host-side modules plus one device-side entry:
 - ``obs.trace``: per-dispatch JSONL spans hooked into the ``stages``
   front door behind ``REPRO_OBS=1`` / ``obs.enable()`` — host-side only,
   so production jaxprs are bit-identical with observability off.
-- ``obs.slo``: rolling rates, latency SLOs with breach events, and a
-  non-raising stall detector for serving loops.
+- ``obs.slo``: latency SLOs with breach events, and a non-raising stall
+  detector for serving loops.
 
 Aggregation/dashboard lives in ``repro.launch.monitor`` (reads what
 ``obs.trace`` writes).
 """
 from repro.obs import metrics, slo, trace                      # noqa: F401
 from repro.obs.metrics import REGISTRY, Histogram, Registry    # noqa: F401
-from repro.obs.slo import RollingRate, SLOTracker, StallDetector  # noqa: F401
+from repro.obs.slo import SLOTracker, StallDetector            # noqa: F401
 from repro.obs.trace import disable, emit, enable, enabled     # noqa: F401
 
 # REPRO_OBS=1 in the environment arms tracing at first import, the same

@@ -14,8 +14,8 @@ reproducible to within one bucket's relative width
 (``10**(1/BUCKETS_PER_DECADE) - 1`` ≈ 12% span → ≤ ~6% error at the
 geometric midpoint), independent of merge order.  The same histogram
 implementation backs ``query.service`` latency reporting,
-``benchmarks/common.timeit`` percentile columns, and ``obs.slo`` rolling
-SLO checks, so BENCH JSONs and live metrics can never disagree on
+``benchmarks/common.timeit`` percentile columns, and ``obs.slo``'s SLO
+checks, so BENCH JSONs and live metrics can never disagree on
 definitions.
 
 The device side is ``fleet_sample(states)`` → ``hier.metrics_snapshot``:
@@ -209,9 +209,10 @@ REGISTRY = Registry()
 
 
 def export_stages_gauges(registry: Optional[Registry] = None) -> dict:
-    """Mirror ``stages.stats()`` — including the per-entry dispatch counts
-    and cumulative dispatch wall — into obs gauges
-    (``stages.<counter>`` / ``stages.entry.<name>.{dispatches,wall_s}``).
+    """Mirror ``stages.stats()`` — including the per-entry dispatch counts,
+    cumulative dispatch wall and set-up seconds — into obs gauges
+    (``stages.<counter>`` /
+    ``stages.entry.<name>.{dispatches,wall_s,lower_s,load_s}``).
     Returns the stats dict it exported."""
     from repro import stages
     reg = registry or REGISTRY
@@ -220,8 +221,8 @@ def export_stages_gauges(registry: Optional[Registry] = None) -> dict:
         if isinstance(v, (int, float)):
             reg.gauge(f"stages.{k}", v)
     for entry, es in s.get("per_entry", {}).items():
-        reg.gauge(f"stages.entry.{entry}.dispatches", es["dispatches"])
-        reg.gauge(f"stages.entry.{entry}.wall_s", es["wall_s"])
+        for k, v in es.items():
+            reg.gauge(f"stages.entry.{entry}.{k}", v)
     return s
 
 

@@ -1,16 +1,13 @@
-"""SLO instrumentation: rolling rates, latency objectives, stall detection.
+"""SLO instrumentation: latency objectives and stall detection.
 
 ROADMAP open item 1 (persistent serving tier) requires p50/p99 latency
-SLOs and fleet-wide rate monitoring before admission control can land.
+SLOs and stall monitoring before admission control can land.
 This module supplies the runtime half:
 
 - ``SLOTracker``: latency tracking through the shared mergeable
   ``obs.metrics.Histogram`` (NOT a sorted list — percentiles stay exact
   under cross-process merge), per-observation threshold checks, breach
   counting, and ``slo_breach`` JSONL events through ``obs.trace``.
-- ``RollingRate``: a bounded-window event-rate tracker for "sustained
-  updates/s over the last W seconds" — the live analogue of the paper's
-  long-run rate plot.
 - ``StallDetector``: the serving-loop cousin of
   ``runtime.straggler.StragglerMonitor`` — same EMA discipline
   (warmup-seeded, clamped update so one stall does not poison the
@@ -22,8 +19,6 @@ latency SLO) and ``runtime/straggler.py`` (eviction/flag events).
 """
 from __future__ import annotations
 
-import time
-from collections import deque
 from typing import Optional
 
 from repro.obs import trace
@@ -78,39 +73,6 @@ class SLOTracker:
                     target_p99_ms=None if self.target_s is None
                     else self.target_s * 1e3,
                     hist=self.hist.to_dict())
-
-
-class RollingRate:
-    """Events/second over a sliding ``window_s`` window.  ``add(n, t)``
-    records ``n`` events at time ``t`` (defaults to now); ``rate(t)``
-    divides the in-window event count by the observed span."""
-
-    def __init__(self, window_s: float = 60.0):
-        self.window_s = float(window_s)
-        self._events: deque = deque()      # (t, n)
-        self._total = 0
-
-    def add(self, n: int, t: Optional[float] = None) -> None:
-        t = time.monotonic() if t is None else t
-        self._events.append((t, n))
-        self._total += n
-        self._evict(t)
-
-    def _evict(self, now: float) -> None:
-        while self._events and self._events[0][0] < now - self.window_s:
-            _, n = self._events.popleft()
-            self._total -= n
-
-    def rate(self, t: Optional[float] = None) -> float:
-        t = time.monotonic() if t is None else t
-        self._evict(t)
-        if not self._events:
-            return 0.0
-        span = t - self._events[0][0]
-        return self._total / span if span > 0 else 0.0
-
-    def total(self) -> int:
-        return self._total
 
 
 class StallDetector:

@@ -2,8 +2,9 @@
 
 Every concrete call through the jit front door (``stages.Wrapped`` →
 ``Compiled``) is a *dispatch*: entry name, config-signature digest, wall
-time, compile seconds when the call triggered staging work, and cache
-provenance (memory / disk / compile).  When tracing is enabled
+time, its start and end on the epoch clock (``t0_ns``/``t1_ns``), compile
+seconds when the call triggered staging work (split into ``lower_s`` and
+``load_s``), and cache provenance (memory / disk / compile).  When tracing is enabled
 (``REPRO_OBS=1`` or ``obs.enable()``), ``stages`` calls the hook
 installed here and each span becomes one JSON line in
 ``<obs_dir>/obs.jsonl``.
@@ -128,7 +129,12 @@ def emit(ev: str, **fields) -> bool:
 
 
 def _on_dispatch(*, entry: str, digest: str, wall_s: float,
-                 compile_s: float, provenance: str) -> None:
-    """The hook ``stages.Wrapped.__call__`` fires per concrete dispatch."""
+                 compile_s: float, lower_s: float, load_s: float,
+                 provenance: str, t0_ns: int, t1_ns: int) -> None:
+    """The hook ``stages.Wrapped.__call__`` fires per concrete dispatch.
+    ``t0_ns``/``t1_ns`` are ``time.time_ns()``, the clock of a profiler
+    trace's ``profile_start_time``, so a record can be placed on it."""
     emit("dispatch", entry=entry, sig=digest, wall_s=round(wall_s, 9),
-         compile_s=round(compile_s, 6), prov=provenance)
+         compile_s=round(compile_s, 6), lower_s=round(lower_s, 6),
+         load_s=round(load_s, 6), prov=provenance, t0_ns=t0_ns,
+         t1_ns=t1_ns)

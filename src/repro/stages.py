@@ -30,6 +30,13 @@ re-compiling.  Launchers call ``set_cache_dir(default_cache_dir())``:
 ``$JAX_COMPILATION_CACHE_DIR`` when it is set, else the fixed
 ``<checkout>/.jax-cache``.  Call it BEFORE the first compile.
 
+Tracing: ``Compiled.op_scopes()`` / ``op_scopes(entry)`` read each
+executable's own optimized HLO into a table from instruction name to
+``op_name`` (the ``jax.named_scope`` path), keyed by HLO module name — the
+join a device trace needs, since its op events carry only instruction
+names.  ``stats()["per_entry"]`` splits each entry's set-up into
+``lower_s`` and ``load_s``.
+
 ``precompile_fleet(cfg)`` enumerates a ``D4MConfig``'s dispatch set
 (instance-batched ingest with/without telemetry, the service query/
 analytics dispatches, the single-instance hier ops, the sharded fns when a
@@ -42,9 +49,10 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import os
+import re
 import threading
 import time
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +68,7 @@ _LOWERED: dict = {}        # full key -> Lowered
 _COMPILED: dict = {}       # full key -> Compiled
 _STATS = dict(lowerings=0, compiles=0, memory_hits=0, disk_hits=0,
               dispatches=0, disk_writes=0)
-_ENTRY_STATS: dict = {}    # entry -> dict(dispatches=int, wall_s=float)
+_ENTRY_STATS: dict = {}    # entry -> dispatches, wall_s, lower_s, load_s
 _DIGESTS: dict = {}        # full key -> short signature digest (hook only)
 _CACHE_DIR: Optional[str] = None
 
@@ -75,10 +83,14 @@ _TRACE_ANNOTATION = None
 
 def set_trace_hook(hook: Optional[Callable], annotation=None) -> None:
     """Install (or clear, with ``None``) the dispatch-span hook.  The hook
-    is called as ``hook(entry=, digest=, wall_s=, compile_s=, provenance=)``
-    after every concrete ``Wrapped`` dispatch; ``annotation``, when given,
-    is a context-manager class (``jax.profiler.TraceAnnotation``) nested
-    around the executable call."""
+    is called as ``hook(entry=, digest=, wall_s=, compile_s=, lower_s=,
+    load_s=, provenance=, t0_ns=, t1_ns=)`` after every concrete ``Wrapped``
+    dispatch: ``compile_s`` = ``lower_s`` (trace and lower) + ``load_s``
+    (XLA compile, or the persistent cache's load), all 0 on a memory hit;
+    ``t0_ns``/``t1_ns`` bracket the dispatch on ``time.time_ns()``, the
+    epoch clock of a profiler trace.  ``annotation``, when given, is a
+    context-manager class (``jax.profiler.TraceAnnotation``) nested around
+    the executable call."""
     global _TRACE_HOOK, _TRACE_ANNOTATION
     _TRACE_HOOK = hook
     _TRACE_ANNOTATION = annotation if hook is not None else None
@@ -271,13 +283,17 @@ def _count(name: str, n: int = 1) -> None:
         _STATS[name] += n
 
 
-def _note_dispatch(entry: str, wall_s: float) -> None:
+def _note_dispatch(entry: str, wall_s: float, lower_s: float,
+                   load_s: float) -> None:
     with _LOCK:
         es = _ENTRY_STATS.get(entry)
         if es is None:
-            es = _ENTRY_STATS[entry] = dict(dispatches=0, wall_s=0.0)
+            es = _ENTRY_STATS[entry] = dict(dispatches=0, wall_s=0.0,
+                                            lower_s=0.0, load_s=0.0)
         es["dispatches"] += 1
         es["wall_s"] += wall_s
+        es["lower_s"] += lower_s
+        es["load_s"] += load_s
 
 
 def _key_digest(key) -> str:
@@ -406,6 +422,7 @@ class Compiled:
         self.key = key
         self.from_disk = from_disk
         self._executable = executable
+        self._scopes: Optional[Dict[str, Dict[str, str]]] = None
 
     def __call__(self, *args):
         return self._executable(*args)
@@ -445,6 +462,17 @@ class Compiled:
 
     def as_text(self) -> str:
         return self._introspect("as_text")
+
+    def op_scopes(self) -> Dict[str, Dict[str, str]]:
+        """``{HLO module name: {instruction name: op_name}}`` of this
+        executable, read from its own optimized HLO (never from a
+        re-lowering, whose instruction names differ): the table that joins
+        a device trace's op events, named by instruction, to the program's
+        ``jax.named_scope`` paths.  An instruction without metadata maps to
+        ``""``.  Raises when the executable cannot print its HLO."""
+        if self._scopes is None:
+            self._scopes = parse_op_scopes(self._executable.as_text())
+        return self._scopes
 
     def memory_analysis(self):
         """``None`` when the executable cannot answer — unlike
@@ -531,17 +559,21 @@ class Wrapped:
         if is_tracing(args):
             return self.fn(*args)
         _count("dispatches")
+        hook = _TRACE_HOOK
+        t0_ns = time.time_ns() if hook is not None else 0
         t0 = time.perf_counter()
         key = self._key(args)
         with _LOCK:
             comp = _COMPILED.get(key)
-        provenance, compile_s = "memory", 0.0
+        provenance, lower_s, load_s = "memory", 0.0, 0.0
         if comp is not None:
             _count("memory_hits")
         else:
             c0 = time.perf_counter()
-            comp = self.lower(*args).compile()
-            compile_s = time.perf_counter() - c0
+            low = self.lower(*args)
+            c1 = time.perf_counter()
+            comp = low.compile()
+            lower_s, load_s = c1 - c0, time.perf_counter() - c1
             provenance = "disk" if comp.from_disk else "compile"
         ann = _TRACE_ANNOTATION
         if ann is not None:
@@ -550,13 +582,14 @@ class Wrapped:
         else:
             out = comp(*args)
         wall = time.perf_counter() - t0
-        _note_dispatch(self.entry, wall)
-        hook = _TRACE_HOOK
+        _note_dispatch(self.entry, wall, lower_s, load_s)
         if hook is not None:
             try:
                 hook(entry=self.entry, digest=_key_digest(key),
-                     wall_s=wall, compile_s=compile_s,
-                     provenance=provenance)
+                     wall_s=wall, compile_s=lower_s + load_s,
+                     lower_s=lower_s, load_s=load_s,
+                     provenance=provenance, t0_ns=t0_ns,
+                     t1_ns=time.time_ns())
             except Exception:
                 pass        # observability must never break the dispatch
         return out
@@ -609,7 +642,9 @@ def stats(reset: bool = False) -> dict:
     ``dispatches`` counts concrete calls through any ``Wrapped``.
 
     ``per_entry`` breaks dispatches down by entry name with cumulative
-    dispatch wall seconds — the gauges ``obs.metrics.export_stages_gauges``
+    dispatch wall seconds and set-up seconds (``lower_s``: trace and lower;
+    ``load_s``: XLA compile or the persistent cache's load; a memory hit
+    adds to neither) — the gauges ``obs.metrics.export_stages_gauges``
     exports.  ``reset=True`` snapshots and zeroes the counters in ONE
     locked step, so concurrent emitters never lose a count between the
     read and the reset (tests/test_obs.py concurrent-emission test)."""
@@ -652,6 +687,55 @@ def lowered_keys() -> Tuple:
     (entry, signature) over this set."""
     with _LOCK:
         return tuple(_LOWERED.keys())
+
+
+# One instruction of an optimized HLO module's text:
+# "  %fusion.80 = f32[17]{0} fusion(...), ..., metadata={op_name="a/b" ...}"
+_HLO_MODULE = re.compile(r"HloModule ([^\s,]+)")
+_HLO_INSTR = re.compile(r"\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
+
+
+def parse_op_scopes(text: str) -> Dict[str, Dict[str, str]]:
+    """``{module name: {instruction name: op_name}}`` from the text of
+    optimized HLO modules (``Compiled.as_text()``).  The ``op_name`` path
+    carries every ``jax.named_scope`` the instruction was traced under
+    (``jit(run)/while/body/cohort.d1/.../canon.sort/sort``); scope names
+    of this program are dotted, ``<layer>.<part>``."""
+    out: Dict[str, Dict[str, str]] = {}
+    table: Dict[str, str] = {}
+    for line in text.splitlines():
+        if line.startswith("HloModule "):
+            table = out.setdefault(_HLO_MODULE.match(line)[1], {})
+            continue
+        m = _HLO_INSTR.match(line)
+        if m:
+            op = _OP_NAME.search(line, m.end())
+            table[m[1]] = op[1] if op else ""
+    return out
+
+
+def op_scopes(entry: Optional[str] = None) -> Dict[str, Dict[str, str]]:
+    """The op-to-scope tables (``Compiled.op_scopes``) of every executable
+    in memory, or of ``entry``'s, merged by HLO module name.  Executables
+    that share a module name (every ``jit(run)``) and disagree on an
+    instruction leave it out: a trace could not tell them apart."""
+    with _LOCK:
+        comps = [c for k, c in _COMPILED.items()
+                 if entry is None or k[0] == entry]
+    out: Dict[str, Dict[str, str]] = {}
+    clash: Dict[str, set] = {}
+    for comp in comps:
+        for module, table in comp.op_scopes().items():
+            merged = out.setdefault(module, {})
+            bad = clash.setdefault(module, set())
+            for name, path in table.items():
+                if merged.setdefault(name, path) != path:
+                    bad.add(name)
+    for module, bad in clash.items():
+        for name in bad:
+            del out[module][name]
+    return out
 
 
 def compiled_for(wrapped: "Wrapped", *args) -> Compiled:

@@ -13,10 +13,12 @@ Covers the acceptance grid:
     jaxpr is bit-identical whether the dispatch hook is installed or not
     (the PR 7 debug-twin discipline applied to obs);
   * dispatch spans: obs.jsonl records are schema-complete with monotonic
-    per-process sequence numbers and memory/disk/compile provenance;
+    per-process sequence numbers, memory/disk/compile provenance, the
+    lower/load split of compile seconds, and start/end on the epoch clock
+    of a profiler trace;
   * per-entry ``stages.stats()`` + the ``stats(reset=True)``
     concurrent-emission guarantee (no count lost between read and reset);
-  * SLO layer: tracker attainment/breaches, stall detector, rolling rate;
+  * SLO layer: tracker attainment/breaches, stall detector;
   * ``run_service`` percentile fix: p50 <= p95 <= p99 <= max from the
     shared histogram, old field names still present;
   * launch/monitor aggregation: multi-process rates, strict schema gate,
@@ -219,6 +221,38 @@ def test_obs_off_adds_zero_lowerings_and_identical_jaxpr(tmp_path):
     assert str(w.lower(states).jaxpr) == jaxpr_off
 
 
+def test_scoped_ingest_adds_no_lowerings_with_tracing_off_or_on(tmp_path):
+    """The ingest program's named scopes are metadata: re-dispatching the
+    warmed scoped program, with tracing off and then on, and reading its
+    op-to-scope table stage nothing new, and its jaxpr is the same with the
+    dispatch hook installed or not."""
+    states = distributed.create_instances(3, CUTS, BLOCK)
+    shape = (3, 4, BLOCK)
+    rows = jnp.arange(np.prod(shape), dtype=jnp.int32).reshape(shape) % 97
+    vals = jnp.ones(shape, jnp.float32)
+    sig = stages.signature_of(cuts=CUTS, block_size=BLOCK, lazy_l0=True,
+                              batch_mode="grouped")
+    w = stream.ingest_instances_jit(sig, with_telemetry=False)
+    jax.block_until_ready(w(states, rows, rows, vals))         # warm
+    before = stages.stats()
+    jax.block_until_ready(w(states, rows, rows, vals))         # off
+    tables = stages.compiled_for(w, states, rows, rows, vals).op_scopes()
+    assert any("cohort.d0" in op for t in tables.values()
+               for op in t.values())
+    jaxpr_off = str(jax.make_jaxpr(w.fn)(states, rows, rows, vals))
+    trace.enable(str(tmp_path / "obs"))
+    try:
+        jax.block_until_ready(w(states, rows, rows, vals))     # on
+        jaxpr_on = str(jax.make_jaxpr(w.fn)(states, rows, rows, vals))
+    finally:
+        trace.disable()
+    after = stages.stats()
+    assert after["lowerings"] == before["lowerings"]
+    assert after["compiles"] == before["compiles"]
+    assert after["memory_hits"] >= before["memory_hits"] + 2
+    assert jaxpr_on == jaxpr_off
+
+
 # ------------------------------------------------------------ trace spans ---
 
 
@@ -242,6 +276,14 @@ def test_dispatch_spans_schema_and_monotonic_seq(obs_dir):
     for s in spans:
         assert s["prov"] in ("memory", "disk", "compile")
         assert s["wall_s"] >= 0 and "sig" in s
+        # on the trace's epoch clock, bracketing the dispatch's wall time
+        assert isinstance(s["t0_ns"], int) and isinstance(s["t1_ns"], int)
+        assert s["t1_ns"] - s["t0_ns"] >= s["wall_s"] * 1e9 - 1e3
+        assert abs(s["t0_ns"] * 1e-9 - s["t"]) < 60
+        assert s["compile_s"] == pytest.approx(s["lower_s"] + s["load_s"],
+                                               abs=2e-6)
+        if s["prov"] == "memory":
+            assert s["lower_s"] == s["load_s"] == 0
     assert any(r["ev"] == "custom" for r in records)
 
 
@@ -334,16 +376,6 @@ def test_stall_detector_flags_slow_step():
     assert d.stalls == 1
     # clamped EMA: the stall did not poison the baseline
     assert d.ema_s < 0.2
-
-
-def test_rolling_rate_windows():
-    r = slo.RollingRate(window_s=10.0)
-    r.add(100, t=0.0)
-    r.add(100, t=5.0)
-    assert r.rate(t=5.0) == pytest.approx(40.0)
-    assert r.total() == 200
-    r.add(50, t=20.0)          # first two fall out of the window
-    assert r.total() == 50
 
 
 # ------------------------------------------------- service percentiles ------

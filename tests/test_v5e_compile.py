@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import stages
 from repro.configs import get_config
 from repro.core import distributed
 from repro.core import semiring as sr_mod
@@ -86,7 +87,9 @@ def _peak(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-def test_ingest_step_compiles(one_chip):
+@pytest.fixture(scope="module")
+def ingest_step(one_chip):
+    """(compiled ingest step, its abstract fleet state) for the chip."""
     states = _states(one_chip)
     stream = tuple(jax.ShapeDtypeStruct((INSTANCES, BLOCKS, CFG.block_size),
                                         d, sharding=one_chip)
@@ -94,7 +97,11 @@ def test_ingest_step_compiles(one_chip):
     w = service.make_ingest_fn(
         SR, use_kernel=CFG.use_kernel, lazy_l0=CFG.lazy_l0, fused=CFG.fused,
         chunk=CFG.chunk, batch_mode=CFG.batch_mode)
-    c = _compile(w.fn, states, *stream, **dict(w.jit_kwargs))
+    return _compile(w.fn, states, *stream, **dict(w.jit_kwargs)), states
+
+
+def test_ingest_step_compiles(ingest_step):
+    c, states = ingest_step
     m = c.memory_analysis()
     state_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(states))
@@ -102,6 +109,30 @@ def test_ingest_step_compiles(one_chip):
     # also covers the tile padding of the per-instance scalars)
     assert m.alias_size_in_bytes >= state_bytes
     assert _peak(c) < HBM_BYTES
+
+
+def test_ingest_step_names_its_layers(ingest_step):
+    """The chip's optimized HLO keeps the program's scopes in each op's
+    ``op_name`` (``stages.parse_op_scopes``): the scatters XLA fuses into
+    ``kCustom`` fusions fall under the canonicalization's value sum or key
+    scatters, and no op falls under two cohort depths."""
+    c, _ = ingest_step
+    text = c.as_text()
+    (table,) = stages.parse_op_scopes(text).values()
+    parts = [op.split("/") for op in table.values()]
+    for scope in ("cohort.d0", "cohort.d1", "cohort.d2", "cohort.take",
+                  "cohort.put", "canon.sort", "canon.value_sum",
+                  "canon.key_scatter"):
+        assert any(scope in p for p in parts), scope
+    assert all(sum(x.startswith("cohort.d") for x in p) <= 1 for p in parts)
+    custom = [line.split(" = ")[0].split()[-1].lstrip("%")
+              for line in text.splitlines() if "kind=kCustom" in line]
+    scatters = [table[n] for n in custom
+                if table[n].split("/")[-1] in ("scatter", "scatter-add")]
+    assert len(scatters) >= 6       # a value sum and two keys per depth
+    for op in scatters:
+        assert {"canon.value_sum", "canon.key_scatter"} & set(
+            op.split("/")), op
 
 
 def test_point_query_compiles(one_chip):
