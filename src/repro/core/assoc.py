@@ -122,40 +122,35 @@ def _canonicalize(hi: Array, lo: Array, val: Array, out_capacity: int,
     entries dropped because they exceeded out_capacity (largest keys drop
     first, preserving the sorted prefix).
 
-    The co-sort, the value sum and the key scatters each carry a
-    ``jax.named_scope`` (``canon.sort``, ``canon.value_sum``,
-    ``canon.key_scatter``) that reaches the compiled ops' ``op_name``, so a
-    device trace can be split by phase (``stages.op_scopes``).  Scopes are
-    metadata only.
+    No scatter: after the co-sort every run of equal keys is contiguous, so
+    a segmented scan leaves each run's total in its last slot, and a second
+    sort moves those slots to the front in key order while every other slot,
+    blanked to the SENTINEL key and the semiring zero, sorts to the tail.
+    The phases carry a ``jax.named_scope`` (``canon.sort``,
+    ``canon.value_sum`` for the scan, ``canon.key_scatter`` for the
+    compaction sort) that reaches the compiled ops' ``op_name``, so a device
+    trace can be split by phase (``stages.op_scopes``).  Scopes are metadata
+    only.
     """
     n = hi.shape[-1]
     with jax.named_scope("canon.sort"):
         hi_s, lo_s, val_s = _sorted_by_key(hi, lo, val)
 
-    prev_same = jnp.concatenate([
-        jnp.zeros((1,), bool),
-        (hi_s[1:] == hi_s[:-1]) & (lo_s[1:] == lo_s[:-1]),
-    ])
-    first = ~prev_same
-    seg_id = jnp.cumsum(first) - 1                       # run index per slot
-    with jax.named_scope("canon.value_sum"):
-        combined = sr.segment_add(val_s, seg_id, n, sorted=True)  # [n]
-
+    prev_same = (hi_s[1:] == hi_s[:-1]) & (lo_s[1:] == lo_s[:-1])
+    first = jnp.concatenate([jnp.ones((1,), bool), ~prev_same])
+    last = jnp.concatenate([~prev_same, jnp.ones((1,), bool)])
     valid = hi_s != SENTINEL
     n_unique = jnp.sum(first & valid).astype(jnp.int32)
 
-    # Scatter each run's key to its run slot.  Duplicate writes within a run
-    # carry identical key values, so write order is immaterial.
-    with jax.named_scope("canon.key_scatter"):
-        out_hi = jnp.full((n,), SENTINEL, jnp.int32).at[seg_id].set(hi_s)
-        out_lo = jnp.full((n,), SENTINEL, jnp.int32).at[seg_id].set(lo_s)
-
     zero = sr_mod.integer_zero(sr, val.dtype)
-    slot = jnp.arange(n)
-    live = slot < n_unique
-    out_hi = jnp.where(live, out_hi, SENTINEL)
-    out_lo = jnp.where(live, out_lo, SENTINEL)
-    out_val = jnp.where(live, combined.astype(val.dtype), zero)
+    with jax.named_scope("canon.value_sum"):
+        totals = _segmented_scan(first, val_s, sr.add, zero)
+
+    with jax.named_scope("canon.key_scatter"):
+        keep = last & valid
+        out_hi, out_lo, out_val = _sorted_by_key(
+            jnp.where(keep, hi_s, SENTINEL), jnp.where(keep, lo_s, SENTINEL),
+            jnp.where(keep, totals, zero))
 
     if out_capacity >= n:
         pad = out_capacity - n
@@ -171,6 +166,54 @@ def _canonicalize(hi: Array, lo: Array, val: Array, out_capacity: int,
 
     nnz = jnp.minimum(n_unique, out_capacity).astype(jnp.int32)
     return AssocSegment(out_hi, out_lo, out_val, nnz), overflow
+
+
+def _segmented_scan(first: Array, val: Array, add, zero) -> Array:
+    """Inclusive scan of ``val`` under ``add`` that restarts at every slot
+    where ``first`` is set: each run's total lands in its last slot.
+
+    Blocked, so the compiled program stays small at millions of slots: the
+    slots are laid out in rows of 128 (one vector register's lanes; rows of
+    1,024 timed within 1% on a v5e), each row is scanned by 7
+    shift-and-combine steps, the rows' own totals are scanned the same way
+    (recursively), and each row then takes in the total carried from the
+    rows before it in one elementwise pass.  Only slots of one run are ever
+    combined, so a plus.times total is exact wherever the run's total is.
+    """
+    n, row = val.shape[0], 128
+    if n <= row:
+        return _shift_scan(first, val, add, zero)[1]
+    rows = -(-n // row)
+    pad = rows * row - n
+    f = jnp.concatenate([first, jnp.ones((pad,), bool)]).reshape(rows, row)
+    v = jnp.concatenate([val, jnp.full((pad,), zero, val.dtype)]
+                        ).reshape(rows, row)
+    f, v = _shift_scan(f, v, add, zero)
+    # row r continues the run open at the end of row r - 1 until its own
+    # first run start; row 0 starts with one (first[0] is always set)
+    ends = _segmented_scan(f[:, -1], v[:, -1], add, zero)
+    carry = jnp.concatenate([jnp.full((1,), zero, val.dtype), ends[:-1]])
+    v = jnp.where(f, v, add(carry[:, None], v))
+    return v.reshape(-1)[:n]
+
+
+def _shift_scan(f: Array, v: Array, add, zero) -> Tuple[Array, Array]:
+    """Segmented inclusive scan along the last axis (Hillis-Steele): after
+    the step of shift k, ``v`` holds the combine of the 2k slots up to and
+    including each slot, cut at the latest run start among them, and ``f``
+    says whether a run starts among them.  Slots before the row's start
+    read as ``zero`` (``add``'s identity) in no run start."""
+    width = v.shape[-1]
+    k = 1
+    while k < width:
+        lead = v.shape[:-1] + (k,)
+        f_in = jnp.concatenate([jnp.zeros(lead, bool), f[..., :-k]], axis=-1)
+        v_in = jnp.concatenate([jnp.full(lead, zero, v.dtype), v[..., :-k]],
+                               axis=-1)
+        v = jnp.where(f, v, add(v_in, v))
+        f = f | f_in
+        k *= 2
+    return f, v
 
 
 def mask_coo(rows: Array, cols: Array, vals: Array,

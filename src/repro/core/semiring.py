@@ -25,9 +25,11 @@ class Semiring:
     """A (add, zero, mul, one) semiring over array values.
 
     ``segment_add`` must implement the same reduction as ``add`` over runs:
-    (vals, segment_ids, num_segments) -> per-segment reduction.  It exists
-    because XLA has dedicated lowerings for segment_{sum,min,max,prod} that
-    are much faster than a generic associative scan.
+    (vals, segment_ids, num_segments) -> per-segment reduction.  The row
+    and column reductions use it, where ids are not contiguous runs.  It
+    lowers to a scatter, which on TPU v5e costs 6-9 ns per element: the
+    merge's canonicalization, whose runs are contiguous after its sort,
+    uses a segmented scan over ``add`` instead (``assoc._segmented_scan``).
     """
 
     name: str

@@ -117,3 +117,89 @@ def test_int_values_max_semiring():
     seg, _ = assoc.from_coo(rows, cols, vals, 4, semiring.MAX_PLUS)
     assert int(assoc.lookup(seg, 1, 1, semiring.MAX_PLUS)) == 9
     assert int(assoc.lookup(seg, 0, 0, semiring.MAX_PLUS)) == 5
+
+
+ALL_SRS = SRS + [semiring.MAX_MIN]
+_ADD = {"plus.times": lambda a, b: a + b, "max.plus": max, "min.plus": min,
+        "max.min": max}
+_ZERO = {"plus.times": 0.0, "max.plus": -np.inf, "min.plus": np.inf,
+         "max.min": -np.inf}
+
+
+def _canon_case(case, rng):
+    """(hi, lo, val, out_capacity) for one ``_canonicalize`` input shape."""
+    S = assoc.SENTINEL
+    if case == "all_unique":
+        n = 300
+        key = rng.permutation(n * 4)[:n]
+        hi, lo = key // 4, key % 4
+    elif case == "one_run":
+        n = 20_000          # a run longer than every row of the blocked scan
+        hi, lo = np.full(n, 7), np.full(n, 3)
+    elif case == "mixed_runs":
+        lengths = rng.choice([1, 1, 1, 2, 5, 129, 700], size=120)
+        hi = np.repeat(np.arange(lengths.size) // 3, lengths)
+        lo = np.repeat(np.arange(lengths.size) % 3, lengths)
+        order = rng.permutation(hi.size)
+        hi, lo = hi[order], lo[order]
+    elif case == "sentinel_masked":
+        n = 500
+        hi, lo = rng.integers(0, 40, n), rng.integers(0, 5, n)
+        dead = rng.random(n) < 0.3
+        hi, lo = np.where(dead, S, hi), np.where(dead, S, lo)
+    elif case == "all_sentinel":
+        hi = lo = np.full(256, S)
+    else:                   # overflow: fewer output slots than unique keys
+        hi, lo = rng.integers(0, 30, 400), rng.integers(0, 4, 400)
+    n = hi.size
+    val = rng.integers(-8, 9, n).astype(np.float32)
+    cap = 37 if case == "overflow" else n + 5
+    return hi.astype(np.int32), lo.astype(np.int32), val, cap
+
+
+def _scatter_prims(jaxpr):
+    found = [e.primitive.name for e in jaxpr.eqns
+             if e.primitive.name.startswith("scatter")]
+    for e in jaxpr.eqns:
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += _scatter_prims(inner)
+    return found
+
+
+@pytest.mark.parametrize("case", ["all_unique", "one_run", "mixed_runs",
+                                  "sentinel_masked", "all_sentinel",
+                                  "overflow"])
+@pytest.mark.parametrize("sr", ALL_SRS, ids=lambda s: s.name)
+def test_canonicalize_matches_a_dict_reference(sr, case):
+    """``_canonicalize`` against a plain dict fold of the same entries, on
+    the run shapes the segmented scan and the compaction sort must get
+    right; integer values make plus.times exact in any summation order.
+    The largest keys drop first past ``out_capacity``, and no scatter is
+    traced."""
+    hi, lo, val, cap = _canon_case(case, np.random.default_rng(14))
+    ref = {}
+    for h, l, v in zip(hi.tolist(), lo.tolist(), val.tolist()):
+        if h != assoc.SENTINEL:
+            ref[h, l] = _ADD[sr.name](ref[h, l], v) if (h, l) in ref else v
+    want = sorted(ref.items())
+    args = (jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(val))
+    seg, ovf = jax.jit(assoc._canonicalize, static_argnums=(3, 4))(
+        *args, cap, sr)
+    nnz = int(seg.nnz)
+    assert nnz == min(len(want), cap)
+    assert int(ovf) == max(len(want) - cap, 0)
+    got = list(zip(zip(np.asarray(seg.hi[:nnz]).tolist(),
+                       np.asarray(seg.lo[:nnz]).tolist()),
+                   np.asarray(seg.val[:nnz]).tolist()))
+    assert got == want[:cap]
+    assert seg.hi.shape == seg.lo.shape == seg.val.shape == (cap,)
+    assert seg.val.dtype == jnp.float32
+    assert np.all(np.asarray(seg.hi[nnz:]) == assoc.SENTINEL)
+    assert np.all(np.asarray(seg.lo[nnz:]) == assoc.SENTINEL)
+    assert np.all(np.asarray(seg.val[nnz:]) == _ZERO[sr.name])
+    jaxpr = jax.make_jaxpr(
+        lambda h, l, v: assoc._canonicalize(h, l, v, cap, sr))(*args)
+    assert _scatter_prims(jaxpr.jaxpr) == []

@@ -16,6 +16,7 @@ instances; ``chip_smoke.py`` runs the same programs at 1,024.
 """
 import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,11 +112,26 @@ def test_ingest_step_compiles(ingest_step):
     assert _peak(c) < HBM_BYTES
 
 
+def _opcodes(text):
+    """``{instruction name: opcode}`` of the optimized HLO text; a fusion's
+    opcode carries its kind (``fusion:kCustom``)."""
+    out = {}
+    for line in text.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = .*? ([a-z][\w\-]*)\(", line)
+        if m:
+            kind = re.search(r"kind=(k\w+)", line)
+            out[m[1]] = m[2] + (f":{kind[1]}" if m[2] == "fusion" and kind
+                                else "")
+    return out
+
+
 def test_ingest_step_names_its_layers(ingest_step):
     """The chip's optimized HLO keeps the program's scopes in each op's
-    ``op_name`` (``stages.parse_op_scopes``): the scatters XLA fuses into
-    ``kCustom`` fusions fall under the canonicalization's value sum or key
-    scatters, and no op falls under two cohort depths."""
+    ``op_name`` (``stages.parse_op_scopes``), no op falls under two cohort
+    depths, and the canonicalization runs no scatter: ``canon.value_sum``
+    is a segmented scan and ``canon.key_scatter`` a compaction sort (a
+    scatter is a ``scatter`` op or, as XLA's TPU backend emits one, a
+    ``kCustom`` fusion)."""
     c, _ = ingest_step
     text = c.as_text()
     (table,) = stages.parse_op_scopes(text).values()
@@ -125,14 +141,17 @@ def test_ingest_step_names_its_layers(ingest_step):
                   "canon.key_scatter"):
         assert any(scope in p for p in parts), scope
     assert all(sum(x.startswith("cohort.d") for x in p) <= 1 for p in parts)
-    custom = [line.split(" = ")[0].split()[-1].lstrip("%")
-              for line in text.splitlines() if "kind=kCustom" in line]
-    scatters = [table[n] for n in custom
-                if table[n].split("/")[-1] in ("scatter", "scatter-add")]
-    assert len(scatters) >= 6       # a value sum and two keys per depth
-    for op in scatters:
-        assert {"canon.value_sum", "canon.key_scatter"} & set(
-            op.split("/")), op
+    opcodes = _opcodes(text)
+    canon = {n: opcodes[n] for n, op in table.items()
+             if n in opcodes and any(x.startswith("canon.")
+                                     for x in op.split("/"))}
+    scatters = {n: (k, table[n]) for n, k in canon.items()
+                if k in ("scatter", "fusion:kCustom")
+                or table[n].split("/")[-1] in ("scatter", "scatter-add")}
+    assert not scatters, scatters
+    assert any("canon.value_sum" in table[n].split("/") for n in canon)
+    assert any(k == "sort" and "canon.key_scatter" in table[n].split("/")
+               for n, k in canon.items())
 
 
 def test_point_query_compiles(one_chip):
